@@ -54,6 +54,7 @@ from repro.core import Graph, PlanConfig, pin_transients, plan
 from repro.core.allocator import resident_bytes
 from repro.core.executor import pack_buffers, unpack_buffer
 from repro.core.plancache import default_cache
+from repro.launch.compile_cache import configure_compile_cache
 from repro.launch.mesh import make_production_mesh, rules_for_mesh
 from repro.launch.steps import make_decode_step, make_prefill_step
 from repro.models.params import ParamDef
@@ -977,6 +978,7 @@ def main() -> None:
                     help="open-loop Poisson arrival rate, requests/tick "
                          "(fleet mode)")
     args = ap.parse_args()
+    configure_compile_cache()
 
     cfg = configs.smoke(args.arch) if args.smoke else configs.get(args.arch)
     model = build_model(cfg)

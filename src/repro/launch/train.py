@@ -22,6 +22,7 @@ import repro.configs as configs
 from repro.checkpoint import CheckpointManager, latest_step, restore
 from repro.configs.base import ShapeConfig
 from repro.data import DataPipeline
+from repro.launch.compile_cache import configure_compile_cache
 from repro.launch.mesh import make_production_mesh, rules_for_mesh
 from repro.launch.steps import make_optimizer, make_train_step
 from repro.models.zoo import build_model
@@ -49,6 +50,7 @@ def main() -> None:
 
     logging.basicConfig(level=logging.INFO,
                         format="%(asctime)s %(name)s %(message)s")
+    configure_compile_cache()
 
     cfg = configs.smoke(args.arch) if args.smoke else configs.get(args.arch)
     model = build_model(cfg)
