@@ -1,0 +1,11 @@
+"""Put the checkout root (for ``chipbench``) and ``src`` (the program) on
+the import path; the tests run on the CPU."""
+
+import sys
+from pathlib import Path
+
+ROOT = Path(__file__).resolve().parents[2]
+for p in (str(ROOT / "src"), str(ROOT)):
+    if p not in sys.path:
+        sys.path.insert(0, p)
+
