@@ -64,6 +64,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core.allocator import ArenaPlan
 from repro.core.graph import Graph, Node
 from repro.core.rewriter import FusedRegion, fuse_alias_chains
@@ -638,6 +639,8 @@ class PlanProgram:
         return arena
 
     def _program(self, arena, ext_flat):
+        # the body runs once per jit trace (or per eager call)
+        obs.count("program.trace")
         body = self._body_fused if self.fuse else self._body_slice
         arena = body(arena, iter(ext_flat))
         outs = tuple(arena_read(arena, self._off[u], self._elems[u],
@@ -656,9 +659,11 @@ class PlanProgram:
             strict: bool = True) -> ExecutionResult:
         """Execute the program; see :func:`execute_plan` for semantics."""
         plan = self.plan
-        ext_vals = self.resolve_ext(inputs)
+        with obs.span("execute.resolve_inputs"):
+            ext_vals = self.resolve_ext(inputs)
         if arena is None:
-            arena = jnp.zeros(self.arena_elems, jnp.float32)
+            with obs.span("execute.alloc_arena"):
+                arena = jnp.zeros(self.arena_elems, jnp.float32)
         elif strict and arena.shape[0] < self.arena_elems:
             raise ExecutorError(
                 f"donated arena has {arena.shape[0]} elements "
@@ -672,12 +677,14 @@ class PlanProgram:
                 f"extent {self.realized_arena_bytes} vs planned "
                 f"{plan.arena_bytes}")
 
-        if jit:
-            if self._jitted is None:
-                self._jitted = jax.jit(self._program, donate_argnums=(0,))
-            outs, _ = self._jitted(arena, ext_vals)
-        else:
-            outs, _ = self._program(arena, ext_vals)
+        with obs.span("execute.dispatch"):
+            if jit:
+                if self._jitted is None:
+                    self._jitted = jax.jit(self._program,
+                                           donate_argnums=(0,))
+                outs, _ = self._jitted(arena, ext_vals)
+            else:
+                outs, _ = self._program(arena, ext_vals)
 
         nds = self.graph.nodes
         return ExecutionResult(
@@ -725,8 +732,10 @@ def compile_plan(
     if prog is not None and prog.graph is g and \
             (registry is None or prog.registry is registry):
         return prog
-    prog = PlanProgram(g, order, plan, fuse=fuse, registry=registry,
-                       impl=impl, interpret=interpret, steps=steps)
+    with obs.span("program.build"):
+        prog = PlanProgram(g, order, plan, fuse=fuse, registry=registry,
+                           impl=impl, interpret=interpret, steps=steps)
+    obs.count("program.build")
     cache[key] = prog
     while len(cache) > _PROGRAM_CACHE_CAP:
         cache.pop(next(iter(cache)))

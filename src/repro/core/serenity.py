@@ -30,6 +30,7 @@ import time
 import warnings
 from typing import Sequence
 
+from repro import obs
 from repro.core.allocator import (
     ArenaPlan,
     SharedArenaPlan,
@@ -738,21 +739,23 @@ def execute(
       asserted equal to the plan's ``peak_bytes`` / ``arena_bytes`` under
       ``strict``).
     """
-    if plan is None:
-        if schedule_kw:
-            if config is not None:
-                raise TypeError("execute: pass either config= or legacy "
-                                "schedule kwargs, not both")
-            _warn_deprecated(
-                "execute(**schedule_kwargs)",
-                "execute(g, config=PlanConfig(...))")
-            config = _legacy_schedule_config(**schedule_kw)
-        res = _plan(g, config, cache=cache)
-        g, order, plan = res.graph, res.order, res.arena
-        steps = res.steps  # pareto plans carry their width-W slots
-    elif order is None:
-        raise ExecutorError("execute: `order` is required when `plan` is "
-                            "supplied (the schedule the plan was built from)")
-    return execute_plan(g, order, plan, inputs, impl=impl,
-                        interpret=interpret, arena=arena, jit=jit,
-                        strict=strict, fuse=fuse, steps=steps)
+    with obs.span("execute"):
+        if plan is None:
+            if schedule_kw:
+                if config is not None:
+                    raise TypeError("execute: pass either config= or legacy "
+                                    "schedule kwargs, not both")
+                _warn_deprecated(
+                    "execute(**schedule_kwargs)",
+                    "execute(g, config=PlanConfig(...))")
+                config = _legacy_schedule_config(**schedule_kw)
+            res = _plan(g, config, cache=cache)
+            g, order, plan = res.graph, res.order, res.arena
+            steps = res.steps  # pareto plans carry their width-W slots
+        elif order is None:
+            raise ExecutorError(
+                "execute: `order` is required when `plan` is supplied (the "
+                "schedule the plan was built from)")
+        return execute_plan(g, order, plan, inputs, impl=impl,
+                            interpret=interpret, arena=arena, jit=jit,
+                            strict=strict, fuse=fuse, steps=steps)
