@@ -212,7 +212,9 @@ def input_nodes(g: Graph) -> list[int]:
 
 def _resolve_inputs(g: Graph, inputs) -> dict[int, "jnp.ndarray"]:
     """Accept {name: array}, {node_id: array}, or a sequence in input-node
-    id order; returns flat float32 arrays keyed by node id."""
+    id order; returns flat float32 arrays keyed by node id.  Only inputs
+    the caller did not give get the default ramp (counter
+    ``execute.default_input``)."""
     ids = input_nodes(g)
     by_name = {g.nodes[i].name: i for i in ids}
     out: dict[int, jnp.ndarray] = {}
@@ -231,9 +233,12 @@ def _resolve_inputs(g: Graph, inputs) -> dict[int, "jnp.ndarray"]:
                 f"graph has {len(ids)} inputs, got {len(vals)}")
         for nid, v in zip(ids, vals):
             out[nid] = jnp.asarray(v, jnp.float32).reshape(-1)
-    for nid in ids:
-        out.setdefault(nid, _ramp(nid, _elems(g.sizes[nid], g.nodes[nid].name))
-                       / 0.05 * 0.3)
+    missing = [nid for nid in ids if nid not in out]
+    for nid in missing:
+        out[nid] = (_ramp(nid, _elems(g.sizes[nid], g.nodes[nid].name))
+                    / 0.05 * 0.3)
+    if missing:
+        obs.count("execute.default_input", len(missing))
     return out
 
 

@@ -7,17 +7,22 @@ a live-byte high-water equal to ``ArenaPlan.peak_bytes`` and a byte extent
 equal to ``ArenaPlan.arena_bytes``.
 """
 
+import re
+
 import numpy as np
 import pytest
 
 jax = pytest.importorskip("jax")
 import jax.numpy as jnp  # noqa: E402
 
+from repro import obs  # noqa: E402
 from repro.core import (  # noqa: E402
     ExecutorError,
     Graph,
+    compile_plan,
     execute,
     execute_plan,
+    executor,
     plan_arena_best,
     run_reference,
     schedule,
@@ -169,6 +174,101 @@ def test_strict_catches_plan_schedule_mismatch():
         pytest.skip("topo order equals DP order on this seed")
     with pytest.raises(ExecutorError, match="realized arena diverges"):
         execute_plan(res.graph, other, res.arena, _inputs(res.graph))
+
+
+# --------------------------------------------------------- input resolution
+
+def _two_input_plan():
+    """Two inputs of different sizes, scheduled in reverse id order: a
+    sequence of inputs that followed the schedule would swap them."""
+    g = Graph.build([
+        dict(name="a", op="input", size_bytes=64),
+        dict(name="b", op="input", size_bytes=96),
+        dict(name="c", op="conv", size_bytes=64, preds=[0, 1]),
+        dict(name="out", op="op", size_bytes=32, preds=[2]),
+    ])
+    order = [1, 0, 2, 3]
+    return g, order, plan_arena_best(g, order)
+
+
+def _two_inputs(g):
+    return [jnp.linspace(-1.0, 1.0, g.sizes[u] // 4) for u in (0, 1)]
+
+
+def test_input_sequence_follows_input_node_id_order():
+    g, order, plan = _two_input_plan()
+    xa, xb = _two_inputs(g)
+    ref = run_reference(g, {"a": xa, "b": xb})
+    assert _max_err(ref, run_reference(g, [xa, xb])) == 0.0
+    ex = execute_plan(g, order, plan, [xa, xb])
+    assert _max_err(ref, ex.outputs) == 0.0
+
+
+@pytest.mark.parametrize("form", ["sequence", "names", "ids"])
+def test_given_inputs_skip_the_default(form, monkeypatch):
+    g, order, plan = _two_input_plan()
+    xa, xb = _two_inputs(g)
+    inputs = {"sequence": [xa, xb], "names": {"a": xa, "b": xb},
+              "ids": {0: xa, 1: xb}}[form]
+    ref = run_reference(g, {"a": xa, "b": xb})
+    before = obs.counters()
+    # the first call traces the program, whose node ops use the ramp too
+    ex = execute(g, inputs, plan, order=order, jit=True)
+    jax.block_until_ready(ex.outputs)
+
+    def no_ramp(uid, n):
+        raise AssertionError(f"default ramp computed for given input {uid}")
+
+    monkeypatch.setattr(executor, "_ramp", no_ramp)
+    for _ in range(10):
+        ex = execute(g, inputs, plan, order=order, jit=True)
+        jax.block_until_ready(ex.outputs)
+    after = obs.counters()
+    assert _max_err(ref, ex.outputs) <= 1e-5
+    assert after.get("execute.default_input", 0) == \
+        before.get("execute.default_input", 0)
+    assert after.get("program.trace", 0) - \
+        before.get("program.trace", 0) == 1
+
+
+@pytest.mark.parametrize("given", [None, "b"], ids=["none", "partial"])
+def test_defaulted_inputs_are_unchanged(given):
+    g, order, plan = _two_input_plan()
+    xb = _two_inputs(g)[1]
+    inputs = None if given is None else {given: xb}
+    prog = compile_plan(g, order, plan)
+    before = obs.counters()
+    ext = executor._resolve_inputs(g, inputs)
+    prog_ext = dict(zip(order[:2], prog.resolve_ext(inputs)))
+    after = obs.counters()
+    defaulted = [0, 1] if given is None else [0]
+    for u in defaulted:
+        want = np.asarray(executor._ramp(u, g.sizes[u] // 4) / 0.05 * 0.3)
+        assert want.dtype == np.float32
+        for got in (ext[u], prog_ext[u]):
+            got = np.asarray(got)
+            assert got.dtype == np.float32
+            assert np.array_equal(got.view(np.uint32), want.view(np.uint32))
+    if given is not None:
+        assert np.array_equal(np.asarray(ext[1]), np.asarray(xb))
+        assert np.array_equal(np.asarray(prog_ext[1]), np.asarray(xb))
+    # two resolutions above, each defaulting the same inputs
+    assert after.get("execute.default_input", 0) - \
+        before.get("execute.default_input", 0) == 2 * len(defaulted)
+
+
+@pytest.mark.parametrize("inputs, msg", [
+    ({"zz": np.zeros(16, np.float32)}, "unknown input 'zz'"),
+    ({2: np.zeros(16, np.float32)}, "unknown input 2"),
+    ([np.zeros(16, np.float32)], "graph has 2 inputs, got 1"),
+    ([np.zeros(16, np.float32)] * 3, "graph has 2 inputs, got 3"),
+], ids=["unknown-name", "non-input-id", "too-few", "too-many"])
+def test_input_errors_are_unchanged(inputs, msg):
+    g, order, plan = _two_input_plan()
+    with pytest.raises(ExecutorError, match=f"^{re.escape(msg)}$"):
+        run_reference(g, inputs)
+    with pytest.raises(ExecutorError, match=f"^{re.escape(msg)}$"):
+        execute_plan(g, order, plan, inputs)
 
 
 # ------------------------------------------------------------ arena kernels
