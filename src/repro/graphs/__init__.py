@@ -1,6 +1,7 @@
 """Benchmark graph generators for the paper's evaluation networks.
 
-darts     -- DARTS learned normal cell (Liu et al., 2019), ImageNet config
+darts     -- DARTS (Liu et al., 2019): the learned normal cell at its ImageNet
+             size, and the published DARTS_V2 ImageNet network
 swiftnet  -- SwiftNet cells (Zhang et al., 2019), HPD config (reconstructed)
 randwire  -- RandWire WS random graphs (Xie et al., 2019), CIFAR configs
 
@@ -12,7 +13,11 @@ partition + isomorphic-cell reuse path end to end; they are benchmark-only
 (a flat exact DP cannot finish them, which is the point).
 """
 
-from repro.graphs.darts import darts_network, darts_normal_cell
+from repro.graphs.darts import (
+    darts_imagenet_network,
+    darts_network,
+    darts_normal_cell,
+)
 from repro.graphs.randwire import randwire_graph, randwire_network
 from repro.graphs.swiftnet import swiftnet_cell, swiftnet_network
 
@@ -33,6 +38,7 @@ FULL_NETWORKS = {
 __all__ = [
     "BENCHMARK_GRAPHS",
     "FULL_NETWORKS",
+    "darts_imagenet_network",
     "darts_network",
     "darts_normal_cell",
     "randwire_graph",
