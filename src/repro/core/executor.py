@@ -646,6 +646,12 @@ class PlanProgram:
     def _program(self, arena, ext_flat):
         # the body runs once per jit trace (or per eager call)
         obs.count("program.trace")
+        # no arena given: the zero arena is made inside this program, so a
+        # step launches no separate fill before it.  It is returned like a
+        # donated one: were it dropped, XLA could fuse the last node's value
+        # into the exit reads, which need not round the same
+        if arena is None:
+            arena = jnp.zeros(self.arena_elems, jnp.float32)
         body = self._body_fused if self.fuse else self._body_slice
         arena = body(arena, iter(ext_flat))
         outs = tuple(arena_read(arena, self._off[u], self._elems[u],
@@ -667,8 +673,7 @@ class PlanProgram:
         with obs.span("execute.resolve_inputs"):
             ext_vals = self.resolve_ext(inputs)
         if arena is None:
-            with obs.span("execute.alloc_arena"):
-                arena = jnp.zeros(self.arena_elems, jnp.float32)
+            obs.count("execute.fresh_arena")
         elif strict and arena.shape[0] < self.arena_elems:
             raise ExecutorError(
                 f"donated arena has {arena.shape[0]} elements "
@@ -779,9 +784,12 @@ def execute_plan(
       interpret: run Pallas kernels in interpret mode (CPU validation).
       arena: optional donated float32 buffer of at least
         ``plan.arena_bytes / 4`` elements to execute in (reused storage,
-        e.g. across decode steps); allocated fresh when ``None``.
+        e.g. across decode steps).  When ``None`` the arena program makes
+        the zero arena itself (no separate fill before dispatch), and the
+        ``execute.fresh_arena`` counter rises by one.
       jit: trace the whole arena program into one jitted function with the
-        arena buffer donated to XLA (trace cached per plan/options).
+        arena buffer donated to XLA (trace cached per plan/options; a call
+        with and one without ``arena`` are two traces of it).
       strict: assert the realized-vs-planned invariant and that the arena
         buffer is large enough.
       fuse: execute in-place alias chains as fused regions — value

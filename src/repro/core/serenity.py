@@ -722,7 +722,9 @@ def execute(
       impl / interpret / arena / jit / strict / fuse: forwarded to
         :func:`repro.core.executor.execute_plan` — slice-op dispatch
         (Pallas on TPU / XLA elsewhere), Pallas interpret mode, an optional
-        donated float32 buffer, whole-program jit, the
+        donated float32 buffer (without one the arena program makes the
+        zero arena itself, counted by ``execute.fresh_arena``),
+        whole-program jit, the
         realized-vs-planned assertion, and fused alias-chain execution
         (DESIGN.md §11).
       steps: width-W time slots the supplied ``plan`` was packed with

@@ -19,10 +19,12 @@ from repro import obs  # noqa: E402
 from repro.core import (  # noqa: E402
     ExecutorError,
     Graph,
+    PlanConfig,
     compile_plan,
     execute,
     execute_plan,
     executor,
+    plan,
     plan_arena_best,
     run_reference,
     schedule,
@@ -163,6 +165,90 @@ def test_jit_and_donated_arena():
     with pytest.raises(ExecutorError, match="donated arena"):
         execute_plan(res.graph, res.order, res.arena, x,
                      arena=jnp.zeros(3, jnp.float32))
+
+
+# ----------------------------------------------- the arena the program makes
+
+def _planned_for_arena(name):
+    if name == "concat_view":
+        g = _concat_depthconv_graph()
+    else:
+        g = BENCHMARK_GRAPHS[name]()
+    res = plan(g, PlanConfig(rewrite=True), cache=False)
+    return res, _inputs(res.graph)
+
+
+def _zero_arena(res):
+    return jnp.zeros(-(-res.arena.arena_bytes // 4), jnp.float32)
+
+
+def _bits(outs):
+    return {k: np.asarray(v).view(np.uint32) for k, v in outs.items()}
+
+
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
+@pytest.mark.parametrize("name, aliased_op", [
+    ("randwire_cifar10", None),
+    ("darts_imagenet_cell", "partial_conv"),
+    ("swiftnet_cell_a", "partial_conv"),
+    ("concat_view", "concat_view"),
+])
+def test_fresh_arena_equals_donated_zero_arena(name, aliased_op, impl):
+    res, x = _planned_for_arena(name)
+    g = res.graph
+    if aliased_op is not None:
+        assert any(nd.op == aliased_op and nd.alias_preds for nd in g.nodes)
+    kw = dict(impl=impl, interpret=impl == "pallas", jit=True)
+    fresh = execute_plan(g, res.order, res.arena, x, **kw)
+    donated = execute_plan(g, res.order, res.arena, x,
+                           arena=_zero_arena(res), **kw)
+    want = _bits(donated.outputs)
+    got = _bits(fresh.outputs)
+    assert set(got) == set(want)
+    for k in want:
+        assert np.array_equal(got[k], want[k]), k
+    assert _max_err(run_reference(g, x), fresh.outputs) <= 1e-5
+    assert fresh.realized_matches_plan
+
+
+def _count(before, after, name):
+    return after.get(name, 0) - before.get(name, 0)
+
+
+def test_fresh_arena_counted_per_call_and_traced_once():
+    res, x = _planned_for_arena("randwire_cifar10")
+    g = res.graph
+    before = obs.counters()
+    for _ in range(10):
+        ex = execute_plan(g, res.order, res.arena, x, jit=True)
+        jax.block_until_ready(ex.outputs)
+    after = obs.counters()
+    assert _count(before, after, "program.trace") == 1
+    assert _count(before, after, "execute.fresh_arena") == 10
+
+    # a donated arena bypasses it: donated, not counted, still checked
+    arena = _zero_arena(res)
+    before = obs.counters()
+    ex = execute_plan(g, res.order, res.arena, x, arena=arena, jit=True)
+    jax.block_until_ready(ex.outputs)
+    after = obs.counters()
+    assert _count(before, after, "execute.fresh_arena") == 0
+    assert arena.is_deleted()
+    with pytest.raises(ExecutorError, match="donated arena"):
+        execute_plan(g, res.order, res.arena, x, jit=True,
+                     arena=jnp.zeros(res.arena.arena_bytes // 4 - 1,
+                                     jnp.float32))
+
+
+def test_eager_fresh_arena_matches_reference():
+    res, x = _planned_for_arena("swiftnet_cell_a")
+    g = res.graph
+    before = obs.counters()
+    ex = execute_plan(g, res.order, res.arena, x, jit=False)
+    after = obs.counters()
+    assert _max_err(run_reference(g, x), ex.outputs) <= 1e-5
+    assert ex.realized_matches_plan
+    assert _count(before, after, "execute.fresh_arena") == 1
 
 
 def test_strict_catches_plan_schedule_mismatch():

@@ -7,6 +7,7 @@ import numpy as np
 import pytest
 
 jax = pytest.importorskip("jax")
+import jax.numpy as jnp  # noqa: E402
 
 from repro import obs  # noqa: E402
 from repro.core import PlanConfig, execute, plan  # noqa: E402
@@ -98,13 +99,16 @@ def _planned():
                 cache=False)
 
 
-def test_execute_records_its_phases_in_order():
+def _records_its_phases_in_order(donate):
     res = _planned()
     x = [np.ones(res.graph.sizes[u] // 4, np.float32)
          for u in range(len(res.graph.nodes))
          if res.graph.nodes[u].op == "input"]
+    arena = (jnp.zeros(-(-res.arena.arena_bytes // 4), jnp.float32)
+             if donate else None)
     with obs.recording() as rec:
-        ex = execute(res.graph, x, res.arena, order=res.order, jit=True)
+        ex = execute(res.graph, x, res.arena, order=res.order, jit=True,
+                     arena=arena)
     jax.block_until_ready(ex.outputs)
     top = [s for s in rec.spans if s.name == "execute"]
     assert len(top) == 1
@@ -112,14 +116,23 @@ def test_execute_records_its_phases_in_order():
     inside = sorted((s for s in rec.spans if s.parent == "execute"
                      and s.name.startswith("execute.")),
                     key=lambda s: s.t0_ns)
+    # no phase of its own for the arena: a fresh one is made in the program
     assert [s.name for s in inside] == [
-        "execute.resolve_inputs", "execute.alloc_arena", "execute.dispatch"]
+        "execute.resolve_inputs", "execute.dispatch"]
     for s in inside:
         assert s.call_id == root.call_id
         assert root.t0_ns <= s.t0_ns <= s.t1_ns <= root.t1_ns
     # a plan's first execution builds its program, inside the call
     (build,) = [s for s in rec.spans if s.name == "program.build"]
     assert build.parent == "execute" and build.call_id == root.call_id
+
+
+def test_execute_records_its_phases_in_order():
+    _records_its_phases_in_order(donate=False)
+
+
+def test_execute_with_a_donated_arena_records_the_same_phases():
+    _records_its_phases_in_order(donate=True)
 
 
 def test_one_build_and_one_trace_over_repeated_calls():
